@@ -19,9 +19,12 @@ and ``reduction_ok`` = 1 iff both are ≥ 2× (``min_frac`` 1.0) — the
 deliverable's headline: ≥ 2× inter-node comm reduction.
 
 **Training rows** actually run ``examples/pretrain_decentralized.py``
-(subprocess; the sweep and the example share one driver path) twice on
-8 host devices — flat ring vs. ``--node-size 2 --wire-dtype bfloat16``
-— and record tokens/sec, comm-MB/worker, and the loss-curve endpoints.
+(its ``main``, in this process: the sweep and the example share one
+driver path, and one process holds the devices) twice — flat ring vs.
+``--node-size 2 --wire-dtype bfloat16`` — and record tokens/sec,
+comm-MB/worker, and the loss-curve endpoints.  They need at least four
+devices (two-level rounds of 2-worker nodes over a TP2 mesh); run on the
+CPU, the sweep asks for 8 host devices before JAX starts.
 ``pretrain/claim_equal_loss`` gates ``hier_loss_ok`` = 1 iff the
 hierarchical final loss is within 5% of the flat run's (``min_frac``
 1.0: equal-or-better final loss at a fraction of the comm volume);
@@ -37,11 +40,9 @@ Standalone runs write ``benchmarks/BENCH_pretrain.json``; under
 ``python -m benchmarks.run pretrain`` the rows land in the main
 ``BENCH_<tag>.json``.
 """
+import importlib.util
 import json
 import os
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
@@ -101,22 +102,21 @@ def analytic_rows() -> dict:
     return {"flat": flat_b, "inter": inter}
 
 
-def _run_driver(tag: str, extra: list) -> dict:
-    out = os.path.join(tempfile.mkdtemp(prefix="pretrain_"), "run.json")
-    cmd = [sys.executable,
-           os.path.join(_REPO, "examples", "pretrain_decentralized.py"),
-           "--devices", "8", "--steps", str(STEPS), "--json-out", out]
-    if MODEL != "full":
-        cmd.append("--quick")
-    cmd += extra
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(_REPO, "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    # the driver forces its own host device count — run it clean
-    env.pop("XLA_FLAGS", None)
-    subprocess.run(cmd, check=True, env=env, cwd=_REPO)
-    with open(out) as f:
-        return json.load(f)
+def _driver():
+    """``examples/pretrain_decentralized.py`` as a module (examples/ is not
+    a package)."""
+    path = os.path.join(_REPO, "examples", "pretrain_decentralized.py")
+    spec = importlib.util.spec_from_file_location("pretrain_decentralized",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _run_driver(extra: list) -> dict:
+    argv = ["--steps", str(STEPS)] + (["--quick"] if MODEL != "full"
+                                      else []) + extra
+    return _driver().main(argv)
 
 
 def train_rows() -> dict:
@@ -125,9 +125,14 @@ def train_rows() -> dict:
         "flat": [],
         "hier": ["--node-size", "2", "--wire-dtype", "bfloat16"],
     }
+    if len(jax.devices()) < 4:
+        raise RuntimeError(
+            f"the training rows need >= 4 devices, found "
+            f"{len(jax.devices())}: on the CPU set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=8 before JAX starts")
     recs = {}
     for tag, extra in runs.items():
-        r = _run_driver(tag, extra)
+        r = _run_driver(extra)
         recs[tag] = r
         us = r["wall_s"] / max(r["steps"], 1) * 1e6
         csv_row(f"pretrain/train_{tag}", us,
@@ -176,6 +181,8 @@ def _write_json(results) -> str:
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(K)
     print("name,us_per_call,derived")
     res = main()
     print(f"bench_json,0.0,path={os.path.relpath(_write_json(res))}")

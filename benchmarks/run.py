@@ -128,6 +128,11 @@ def _write_bench_json(sections, wall_s) -> str:
 
 def main() -> None:
     want = [a for a in sys.argv[1:] if a in SECTIONS] or SECTIONS
+    if "pretrain" in want:
+        # its training rows run on a multi-device mesh; on the CPU that
+        # takes forced host devices, set before any section starts JAX
+        from repro.launch.mesh import force_host_devices
+        force_host_devices(8)
     print("name,us_per_call,derived")
     t0 = time.time()
     if "fig1" in want:

@@ -23,8 +23,11 @@ from repro.core import (CPDSGDM, CPDSGDMConfig, IdentityCompressor,
 from repro.core.gossip import DenseComm
 from repro.core.topology import exponential, ring, torus
 from repro.data.synthetic import LMStreamCfg, lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import make_model
 from repro.train.trainer import SimTrainer
+
+enable_compile_cache()
 
 K = 8
 STEPS = int(os.environ.get("ABLATION_STEPS", "50"))

@@ -23,10 +23,13 @@ from repro.core import make_optimizer
 from repro.core.gossip import DenseComm
 from repro.core.topology import ring
 from repro.data.synthetic import ClassStreamCfg, class_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.resnet import resnet20_init, resnet20_loss
 from repro.train.trainer import SimTrainer
 
 import jax.numpy as jnp
+
+enable_compile_cache()
 
 K = 8
 STEPS = int(os.environ.get("ABLATION_STEPS", "50"))

@@ -21,8 +21,11 @@ from repro.core import (CPDSGDMConfig, CPDSGDM, PDSGDM, PDSGDMConfig,
 from repro.core.gossip import DenseComm
 from repro.core.topology import one_peer_exponential_schedule, ring
 from repro.data.synthetic import LMStreamCfg, lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import make_model
 from repro.train.trainer import SimTrainer
+
+enable_compile_cache()
 
 K = 8       # workers on a ring (the paper's setup)
 STEPS = 60

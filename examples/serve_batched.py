@@ -13,8 +13,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import make_model
 from repro.serve.serving import generate
+
+enable_compile_cache()
 
 BATCH, PROMPT, NEW = 4, 24, 24
 

@@ -1,14 +1,1 @@
-"""repro — PD-SGDM / CPD-SGDM decentralized training on JAX.
-
-Package-level invariant: sharding-invariant RNG.  With the legacy
-(non-partitionable) threefry lowering, GSPMD partitioning changes the
-values drawn inside jitted functions with ``out_shardings`` — so
-``TrainPack.init_fn`` on the mesh and the dense single-process simulation
-would start from *different* x₀ and every dense-vs-sharded equivalence
-contract would silently fail.  Flip the flag once, before anything traces,
-so both backends draw identical randoms regardless of partitioning.
-(JAX enables this by default in later releases.)
-"""
-import jax as _jax
-
-_jax.config.update("jax_threefry_partitionable", True)
+"""repro — PD-SGDM / CPD-SGDM decentralized training on JAX."""

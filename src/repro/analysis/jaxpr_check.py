@@ -351,21 +351,8 @@ def trace_round(opt, params, p: int, *, kernel: bool = False, x64: bool = False,
             return opt.kernel_round(state, params, grads_fn, batches)
         return opt.round(state, params, grads_fn, batches)
 
-    if x64:
-        from jax.experimental import enable_x64
-        ctx = enable_x64
-    else:
-        ctx = _null_ctx
-    with ctx():
+    with jax.enable_x64(x64):
         return jax.make_jaxpr(one_round)(params, state, batches)
-
-
-class _null_ctx:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
 
 
 # ------------------------------------------------------------------ aggregate

@@ -12,7 +12,6 @@ import sys           # noqa: E402
 import time          # noqa: E402
 
 import jax           # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
 
 """Static-analysis driver: the round contract, checked across the grid.
 
@@ -233,7 +232,7 @@ def phase_sharded(full: bool) -> list:
             v += jc.check_gossip_boundary(jx, expected=expected)
         if schedule != "static":
             v += jc.check_schedule_switch(jx, pack.opt.comm.period)
-        with enable_x64():
+        with jax.enable_x64(True):
             jx64 = jax.make_jaxpr(pack.train_round)(*args)
         v += jc.check_no_f64(jx64)
         # schedules vary wire bytes by round; byte equality is round-0 only
@@ -293,7 +292,7 @@ def phase_sharded(full: bool) -> list:
             v += jc.check_gossip_boundary(jx, expected=expected)
         if schedule != "static":
             v += jc.check_schedule_switch(jx, pack.opt.comm.period)
-        with enable_x64():
+        with jax.enable_x64(True):
             jx64 = jax.make_jaxpr(pack.train_round)(*args)
         v += jc.check_no_f64(jx64)
         v += hc.check_sharded_round(pack, check_bytes=(schedule == "static"),

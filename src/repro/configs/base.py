@@ -189,10 +189,9 @@ class OptimCfg:
     wire_dtype: str = "float32"
     # Pallas execution path: run the fused round on the flatten-once
     # (rows, 1024) kernel layout — momentum scan, gossip mix and CPD's
-    # packed sign wire in one layout, flattened once per round.  The
-    # recommended configuration on TPU (`--use-kernel` in launch.train);
-    # off by default here because this container only has the interpret-
-    # mode correctness harness.
+    # packed sign wire in one layout, flattened once per round
+    # (`--use-kernel` in launch.train).  Off by default: whether it beats
+    # the tree path on a TPU has not been measured (ROADMAP S3).
     use_kernel: bool = False
     # force Pallas interpret mode on/off; None = auto (interpret off-TPU)
     kernel_interpret: Optional[bool] = None

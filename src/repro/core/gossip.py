@@ -192,6 +192,13 @@ class CommBackend:
             self.topology = first
 
 
+def _mm(w, x):
+    """f32 mixing product at full precision: a TPU's default f32 matmul
+    rounds its operands to bf16 (W = 1/3 becomes 0.33398), which would
+    make the simulation drift from the sharded AXPY gossip."""
+    return jnp.matmul(w, x, precision=jax.lax.Precision.HIGHEST)
+
+
 @dataclasses.dataclass
 class DenseComm(CommBackend):
     """Simulation backend: leaves are worker-stacked, leading dim K.
@@ -316,9 +323,9 @@ class DenseComm(CommBackend):
             if self.wire_dtype == "bfloat16":
                 diag = jnp.diagonal(R)
                 wire = xa.astype(jnp.bfloat16).astype(jnp.float32)
-                mixed = diag[:, None] * xa + (R - jnp.diag(diag)) @ wire
+                mixed = diag[:, None] * xa + _mm(R - jnp.diag(diag), wire)
             else:
-                mixed = R @ xa
+                mixed = _mm(R, xa)
             out = jnp.broadcast_to(mixed[:, None, :], flat.shape)
             return out.astype(leaf.dtype).reshape(leaf.shape)
 
@@ -349,9 +356,9 @@ class DenseComm(CommBackend):
                 # backend's wire semantics, simulated
                 diag = jnp.diagonal(W)
                 wire = flat.astype(jnp.bfloat16).astype(jnp.float32)
-                out = diag[:, None] * flat + (W - jnp.diag(diag)) @ wire
+                out = diag[:, None] * flat + _mm(W - jnp.diag(diag), wire)
             else:
-                out = W @ flat
+                out = _mm(W, flat)
             return out.astype(leaf.dtype).reshape(leaf.shape)
 
         return jax.tree_util.tree_map(_mix, tree)

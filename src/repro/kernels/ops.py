@@ -18,8 +18,8 @@ an arbitrary pytree and that layout:
     sign kernel so tail-block scales match the padding-masked oracle.
 
 ``interpret`` defaults to :func:`repro.kernels.default_interpret` —
-lazily evaluated, True off-TPU (this container is CPU-only: TPU is the
-*target*, interpret mode is the correctness harness).
+lazily evaluated: compiled kernels on a TPU, interpret mode (the CPU
+correctness harness) anywhere else.
 """
 from __future__ import annotations
 

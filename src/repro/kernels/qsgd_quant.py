@@ -7,10 +7,10 @@ flatten-once (rows, 1024) layout.
 
 One *row* is one quantization block: ``norm = max |x|`` over the row, then
 ``u = round(x / norm · s) + s`` ∈ [0, 2s] packed ``8/bits`` elements per
-byte with ``bits = qsgd_bits(levels)`` ∈ {2, 4, 8} (same weighted-sum
-in-register bit-gather as the sign kernel — lane shifts within a vreg, no
-HBM round-trip).  Deterministic nearest rounding keeps the operator a
-δ-contraction; the jnp oracle is ``repro.core.wire.qsgd_rows``.
+byte with ``bits = qsgd_bits(levels)`` ∈ {2, 4, 8} (the same exact
+MXU bit-gather as the sign kernel, ``repro.kernels.bitpack``).
+Deterministic nearest rounding keeps the operator a δ-contraction; the
+jnp oracle is ``repro.core.wire.qsgd_rows``.
 
 Padding contract: the ``KernelPlan`` zero-pads tail rows, and 0 quantizes
 to the center level u = s which dequantizes back to exactly 0, so no
@@ -29,37 +29,27 @@ from jax.experimental import pallas as pl
 # the bit-width rule is owned by the wire codec (one source of truth for
 # the kernel, the jnp oracle, and the byte accounting)
 from repro.core.wire import qsgd_bits as _bits
-from repro.kernels import LANE, default_interpret
+from repro.kernels import LANE, bitpack, default_interpret
 
 __all__ = ["qsgd_quant_pallas", "qsgd_dequant_pallas", "LANE", "BLOCK_ROWS"]
 
 BLOCK_ROWS = 256
 
 
-def _quant_kernel(x_ref, packed_ref, norm_ref, *, levels, bits):
+def _quant_kernel(x_ref, w_ref, packed_ref, norm_ref, *, levels, bits):
     x = x_ref[...]                                    # (BR, 1024) f32
-    br = x.shape[0]
-    vpb = 8 // bits
     s = jnp.float32(levels)
     norm = jnp.max(jnp.abs(x), axis=1, keepdims=True)
     norm_ref[...] = norm
     # scale-first, single elementwise multiply — mirrors the jnp oracle so
     # no lowering can reassociate the div/mul chain (see wire.qsgd_rows)
     qscale = s / jnp.maximum(norm, 1e-30)
-    u = (jnp.round(x * qscale) + s).astype(jnp.uint8)
-    grouped = u.reshape(br, LANE // vpb, vpb)
-    weights = (jnp.uint8(1) << (jnp.uint8(bits)
-                                * jnp.arange(vpb, dtype=jnp.uint8)))
-    packed_ref[...] = jnp.sum(grouped * weights, axis=-1).astype(jnp.uint8)
+    u = jnp.round(x * qscale) + s                     # integers in [0, 2s]
+    packed_ref[...] = bitpack.pack_fields(u, w_ref[...], bits=bits)
 
 
-def _dequant_kernel(packed_ref, norm_ref, out_ref, *, levels, bits):
-    pk = packed_ref[...]                              # (BR, 1024·bits/8) u8
-    br = pk.shape[0]
-    vpb = 8 // bits
-    mask = jnp.uint8((1 << bits) - 1)
-    shifts = jnp.uint8(bits) * jnp.arange(vpb, dtype=jnp.uint8)
-    u = (pk[:, :, None] >> shifts) & mask
+def _dequant_kernel(packed_ref, norm_ref, s_ref, out_ref, *, levels, bits):
+    u = bitpack.unpack_fields(packed_ref[...], s_ref[...], bits=bits)
     s = jnp.float32(levels)
     # mirrors wire.qsgd_rows_unpack's bit-determinism contract: reciprocal
     # constant (no constant division), scale formed first (single
@@ -67,7 +57,7 @@ def _dequant_kernel(packed_ref, norm_ref, out_ref, *, levels, bits):
     inv_s = jnp.float32(np.float32(1.0) / np.float32(levels))
     norm = norm_ref[...]
     scale = inv_s * norm
-    vals = (u.reshape(br, LANE).astype(jnp.float32) - s) * scale
+    vals = (u.astype(jnp.float32) - s) * scale
     out_ref[...] = jnp.where(norm > 0, vals, jnp.float32(0.0))
 
 
@@ -83,16 +73,18 @@ def qsgd_quant_pallas(x, *, levels: int, interpret: bool | None = None):
     packed_w = LANE * bits // 8
     grid = (rows // BLOCK_ROWS,)
     kernel = functools.partial(_quant_kernel, levels=levels, bits=bits)
-    return pl.pallas_call(
+    packed, norms = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
+                  pl.BlockSpec((LANE, packed_w), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((BLOCK_ROWS, packed_w), lambda i: (i, 0)),
                    pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, packed_w), jnp.uint8),
+        out_shape=[jax.ShapeDtypeStruct((rows, packed_w), jnp.int8),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=interpret,
-    )(x.astype(jnp.float32))
+    )(x.astype(jnp.float32), bitpack.pack_matrix(bits))
+    return bitpack.to_wire(packed), norms
 
 
 @functools.partial(jax.jit, static_argnames=("levels", "interpret"))
@@ -103,16 +95,18 @@ def qsgd_dequant_pallas(packed, norms, *, levels: int,
         interpret = default_interpret()
     rows = packed.shape[0]
     bits = _bits(levels)
-    assert packed.shape[1] == LANE * bits // 8 and rows % BLOCK_ROWS == 0
+    packed_w = LANE * bits // 8
+    assert packed.shape[1] == packed_w and rows % BLOCK_ROWS == 0
     grid = (rows // BLOCK_ROWS,)
     kernel = functools.partial(_dequant_kernel, levels=levels, bits=bits)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((BLOCK_ROWS, LANE * bits // 8),
-                               lambda i: (i, 0)),
-                  pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((BLOCK_ROWS, packed_w), lambda i: (i, 0)),
+                  pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((packed_w, LANE), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, LANE), jnp.float32)],
         interpret=interpret,
-    )(packed, norms.reshape(rows, 1).astype(jnp.float32))[0]
+    )(bitpack.from_wire(packed), norms.reshape(rows, 1).astype(jnp.float32),
+      bitpack.spread_matrix(bits))[0]

@@ -18,10 +18,11 @@ jnp oracle (``repro.core.compression.sign_pack``) computes.  Without it the
 tail block's scale would be deflated by ``n_valid/1024``.  Rows that are
 pure alignment padding carry count 0 and produce scale 0.
 
-TPU adaptation note: the bit-gather uses an in-register reshape
-(rows, 128, 8) → weighted sum over the last (sublane-contiguous) axis; on
-real hardware this lowers to lane shifts within a vreg, not an HBM
-round-trip.  Validated in interpret mode against ``repro.core.compression``.
+The bit-gather is an exact MXU product with a constant (1024, 128)
+weight matrix, and the unpack its transpose plus a per-lane shift
+(``repro.kernels.bitpack``): Mosaic has no u8 arithmetic or lane-splitting
+reshape.  Bit-exact against ``repro.core.compression`` and the matrix
+oracle ``repro.kernels.ref.sign_pack_rows_ref``.
 """
 from __future__ import annotations
 
@@ -31,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import LANE, default_interpret
+from repro.kernels import LANE, bitpack, default_interpret
 
 __all__ = ["sign_pack_pallas", "sign_unpack_pallas", "LANE", "PACKED",
            "BLOCK_ROWS"]
@@ -41,27 +42,22 @@ PACKED = LANE // 8   # bytes per packed row
 BLOCK_ROWS = 256
 
 
-def _pack_kernel(x_ref, cnt_ref, packed_ref, scale_ref):
+def _pack_kernel(x_ref, cnt_ref, w_ref, packed_ref, scale_ref):
     x = x_ref[...]                                   # (BR, 1024) f32
     cnt = cnt_ref[...]                               # (BR, 1) f32 valid count
-    br = x.shape[0]
     # padded entries are exactly 0, so the |x| row sum already excludes
     # them; only the divisor needs the true length (bit-exact vs the
     # padding-masked oracle)
     scale_ref[...] = (jnp.sum(jnp.abs(x), axis=1, keepdims=True)
                       / jnp.maximum(cnt, 1.0))
-    bits = (x >= 0).astype(jnp.uint8).reshape(br, PACKED, 8)
-    weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))
-    packed_ref[...] = jnp.sum(bits * weights, axis=-1).astype(jnp.uint8)
+    packed_ref[...] = bitpack.pack_fields(
+        (x >= 0).astype(jnp.float32), w_ref[...], bits=1)
 
 
-def _unpack_kernel(packed_ref, scale_ref, out_ref):
-    pk = packed_ref[...]                             # (BR, 128) uint8
-    br = pk.shape[0]
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (pk[:, :, None] >> shifts) & jnp.uint8(1)
+def _unpack_kernel(packed_ref, scale_ref, s_ref, out_ref):
+    bits = bitpack.unpack_fields(packed_ref[...], s_ref[...], bits=1)
     signs = bits.astype(jnp.float32) * 2.0 - 1.0
-    out_ref[...] = signs.reshape(br, LANE) * scale_ref[...]
+    out_ref[...] = signs * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -78,17 +74,20 @@ def sign_pack_pallas(x, counts=None, *, interpret: bool | None = None):
     if counts is None:
         counts = jnp.full((rows, 1), float(LANE), jnp.float32)
     grid = (rows // BLOCK_ROWS,)
-    return pl.pallas_call(
+    packed, scales = pl.pallas_call(
         _pack_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
-                  pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0))],
+                  pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((LANE, PACKED), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((BLOCK_ROWS, PACKED), lambda i: (i, 0)),
                    pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, PACKED), jnp.uint8),
+        out_shape=[jax.ShapeDtypeStruct((rows, PACKED), jnp.int8),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=interpret,
-    )(x.astype(jnp.float32), counts.reshape(rows, 1).astype(jnp.float32))
+    )(x.astype(jnp.float32), counts.reshape(rows, 1).astype(jnp.float32),
+      bitpack.pack_matrix(1))
+    return bitpack.to_wire(packed), scales
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -103,8 +102,10 @@ def sign_unpack_pallas(packed, scales, *, interpret: bool | None = None):
         _unpack_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((BLOCK_ROWS, PACKED), lambda i: (i, 0)),
-                  pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0))],
+                  pl.BlockSpec((BLOCK_ROWS, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((PACKED, LANE), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, LANE), jnp.float32)],
         interpret=interpret,
-    )(packed, scales.reshape(rows, 1).astype(jnp.float32))[0]
+    )(bitpack.from_wire(packed), scales.reshape(rows, 1).astype(jnp.float32),
+      bitpack.spread_matrix(1))[0]

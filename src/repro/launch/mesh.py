@@ -5,9 +5,14 @@ inside functions only.
 """
 from __future__ import annotations
 
+import os
+
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh", "HW"]
+__all__ = ["make_production_mesh", "make_mesh", "worker_mesh",
+           "force_host_devices", "HW"]
+
+_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count="
 
 
 class HW:
@@ -20,11 +25,26 @@ class HW:
 
 
 def make_mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):   # jax >= 0.5 explicit-axes API
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def worker_mesh(data_axis: int, model_axis: int):
+    """A ("data", "model") mesh over the attached devices: ``data_axis`` ×
+    ``model_axis`` when there are enough, else one worker per device."""
+    n_dev = len(jax.devices())
+    if n_dev >= data_axis * model_axis:
+        return make_mesh((data_axis, model_axis), ("data", "model"))
+    return make_mesh((n_dev, 1), ("data", "model"))
+
+
+def force_host_devices(n: int) -> None:
+    """Ask XLA's CPU backend for ``n`` host devices (CPU rehearsals of
+    multi-device paths).  Keeps any other ``XLA_FLAGS``; takes effect only
+    if no JAX backend has started yet, and a TPU ignores it."""
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if not f.startswith(_HOST_DEVICES_FLAG)]
+    os.environ["XLA_FLAGS"] = " ".join(flags + [f"{_HOST_DEVICES_FLAG}{n}"])
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,6 +1,6 @@
 """Distributed runtime: builds jit-able train / prefill / decode steps.
 
-Structure of one training iteration (see DESIGN.md):
+Structure of one training iteration (docs/ARCHITECTURE.md):
 
   1. per-worker forward+backward — ``vmap`` over the stacked worker dim, in
      the pjit/GSPMD domain (XLA inserts the tensor-parallel collectives and,
@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 import math
 from typing import Callable, Dict, Optional
 
@@ -43,20 +42,9 @@ from repro.models import make_model
 __all__ = ["build_comm", "build_train", "build_serve", "TrainPack",
            "ServePack", "make_shd"]
 
-if hasattr(jax, "shard_map"):           # stable top-level API
-    _shard_map_compat = jax.shard_map
-else:                                   # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-# the replication-check kwarg was renamed check_rep -> check_vma; key on the
-# signature, not the jax version, so the mid-range releases work too
-_CHECK_KW = ("check_vma" if "check_vma" in inspect.signature(
-    _shard_map_compat).parameters else "check_rep")
-
 
 def _smap(mesh):
-    return functools.partial(_shard_map_compat, mesh=mesh,
-                             **{_CHECK_KW: False})
+    return functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
 
 def make_shd(layout: Layout, parallel):

@@ -3,16 +3,17 @@
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --smoke \
       --optimizer pd_sgdm --steps 50 --devices 8
 
-On this CPU container ``--devices N`` forces N host devices and a debug mesh
-(the production path is identical code on a real mesh).  ``--smoke`` selects
-the reduced config; the full configs are exercised by ``dryrun``.
+On a TPU the mesh covers the attached chips.  On the CPU, ``--devices N``
+forces N host devices for a debug mesh (the same code as on a real mesh).
+``--smoke`` selects the reduced config; without it the published widths
+run.  ``chip_smoke.py`` drives the same :func:`run_config` →
+``build_train`` → ``ShardedTrainer`` path on the chip.
 """
 import argparse
-import os
 import sys
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--optimizer", default=None,
@@ -76,21 +77,14 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--resume", action="store_true",
                     help="continue from the latest checkpoint in --ckpt-dir")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
 
+def run_config(args):
+    """The ``RunCfg`` the command line asks for."""
     import dataclasses
 
-    import jax
-
     from repro.configs.registry import get_config, get_smoke_config
-    from repro.configs.shapes import InputShape, train_batch_arrays
-    from repro.launch.mesh import make_mesh
-    from repro.launch.runtime import build_train
-    from repro.train.trainer import ShardedTrainer
 
     run = (get_smoke_config if args.smoke else get_config)(args.arch)
     optim = run.optim
@@ -133,15 +127,40 @@ def main():
     if args.inter_codec:
         parallel = dataclasses.replace(parallel,
                                        inter_codec=args.inter_codec)
-    run = dataclasses.replace(run, optim=optim, parallel=parallel)
+    return dataclasses.replace(run, optim=optim, parallel=parallel)
 
-    n_dev = len(jax.devices())
-    if n_dev >= args.data_axis * args.model_axis:
-        mesh = make_mesh((args.data_axis, args.model_axis),
-                         ("data", "model"))
-    else:
-        mesh = make_mesh((n_dev, 1), ("data", "model"))
 
+def batch_fn_for(run, n_workers: int, args):
+    """Step ``t`` → the seeded random token batch of every worker."""
+    import jax
+
+    from repro.configs.shapes import train_batch_arrays
+
+    def batch_fn(t):
+        return train_batch_arrays(
+            run.model, n_workers, args.global_batch // n_workers,
+            args.seq_len, jax.random.fold_in(jax.random.PRNGKey(1), t))
+    return batch_fn
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.devices:
+        from repro.launch.mesh import force_host_devices
+        force_host_devices(args.devices)
+
+    import jax
+
+    from repro.configs.shapes import InputShape
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import worker_mesh
+    from repro.launch.runtime import build_train
+    from repro.train.trainer import ShardedTrainer
+
+    enable_compile_cache()
+    run = run_config(args)
+    optim = run.optim
+    mesh = worker_mesh(args.data_axis, args.model_axis)
     shape = InputShape("cli", args.seq_len, args.global_batch, "train")
     pack = build_train(run, mesh, shape)
     n_w = pack.layout.n_workers
@@ -149,11 +168,7 @@ def main():
           f"workers={n_w} kernel={optim.use_kernel} "
           f"overlap={optim.overlap} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
-
-    def batch_fn(t):
-        return train_batch_arrays(
-            run.model, n_w, args.global_batch // n_w, args.seq_len,
-            jax.random.fold_in(jax.random.PRNGKey(1), t))
+    batch_fn = batch_fn_for(run, n_w, args)
 
     trainer = ShardedTrainer(pack, ckpt_dir=args.ckpt_dir,
                              ckpt_every=args.ckpt_every)

@@ -100,11 +100,10 @@ def test_qsgd_tree_no_f64():
 def test_topk_kernel_no_f64():
     """Same regression class for the topk select/scatter kernels."""
     from repro.kernels import topk_select
-    from jax.experimental import enable_x64
     rows = topk_select.BLOCK_ROWS
     x = jnp.zeros((rows, 1024), jnp.float32)
     cnt = jnp.full((rows, 1), 1024.0, jnp.float32)
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(
             lambda x, c: topk_select.topk_select_pallas(
                 x, c, fraction=0.01, interpret=True))(x, cnt)

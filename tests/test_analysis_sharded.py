@@ -50,10 +50,7 @@ _SCRIPT_GREEN = _PRELUDE + textwrap.dedent("""
 
 _SCRIPT_SEEDED_ALLGATHER = _PRELUDE + textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     pack = pack_for("pd_sgdm")
     mesh = pack.layout.mesh

@@ -20,6 +20,20 @@ def test_dense_mix_equals_matmul():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
+@pytest.mark.parametrize("wire_dtype", ["float32", "bfloat16"])
+def test_dense_mix_matmul_is_full_precision(wire_dtype):
+    """The W-matmul must not run at a TPU's default f32 precision, which
+    rounds W to bf16 (1/3 → 0.33398) and drifts from the sharded AXPY."""
+    comm = DenseComm(ring(8), wire_dtype=wire_dtype)
+    jx = jax.make_jaxpr(comm.mix)({"w": jnp.zeros((8, 5, 3))})
+    dots = [e for e in jx.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        assert e.params["precision"] in (
+            jax.lax.Precision.HIGHEST,
+            (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)), e
+
+
 def test_dense_shift_views_roll():
     comm = DenseComm(ring(4))
     x = jnp.arange(4.0)[:, None]
