@@ -28,6 +28,13 @@ from repro.core.gossip import CommBackend, DenseComm, HierarchicalComm
 
 __all__ = ["PDSGDMConfig", "PDSGDM"]
 
+# Named scopes (``jax.named_scope``) of the fused round, set in ``round`` and
+# ``kernel_round`` so every optimizer and both trainers carry them: the
+# compiled HLO's op metadata holds them, so a device trace can split a round.
+SCOPE_GRAD = "grad"              # the loss and its gradient (fwd, bwd, remat)
+SCOPE_LOCAL_STEP = "local_step"  # the momentum and weight update
+SCOPE_GOSSIP = "gossip"          # wire casts, codec, the exchange, the mix
+
 
 def _tree_map(f, *trees):
     return jax.tree_util.tree_map(f, *trees)
@@ -283,32 +290,40 @@ class PDSGDM:
                 overlap_apply = self.overlap_apply
             if overlap_refresh is None and self.overlap_refreshes:
                 overlap_refresh = self.overlap_step_refresh
-            delta = overlap_begin(state) if (gossip or overlap_refresh) \
-                else None
+            delta = None
+            if gossip or overlap_refresh:
+                with jax.named_scope(SCOPE_GOSSIP):
+                    delta = overlap_begin(state)
 
             def body(carry, batch):
                 params, state = carry
-                loss, grads = grads_fn(params, batch)
-                params, state = local_step(state, params, grads)
-                if overlap_refresh is not None:
-                    state = overlap_refresh(state, delta)
+                with jax.named_scope(SCOPE_GRAD):
+                    loss, grads = grads_fn(params, batch)
+                with jax.named_scope(SCOPE_LOCAL_STEP):
+                    params, state = local_step(state, params, grads)
+                    if overlap_refresh is not None:
+                        state = overlap_refresh(state, delta)
                 return (params, state), loss
 
             (params, state), losses = jax.lax.scan(body, (params, state),
                                                    batches)
             if gossip:
-                params, state = overlap_apply(state, params, delta)
+                with jax.named_scope(SCOPE_GOSSIP):
+                    params, state = overlap_apply(state, params, delta)
             return params, state, losses
 
         def body(carry, batch):
             params, state = carry
-            loss, grads = grads_fn(params, batch)
-            params, state = local_step(state, params, grads)
+            with jax.named_scope(SCOPE_GRAD):
+                loss, grads = grads_fn(params, batch)
+            with jax.named_scope(SCOPE_LOCAL_STEP):
+                params, state = local_step(state, params, grads)
             return (params, state), loss
 
         (params, state), losses = jax.lax.scan(body, (params, state), batches)
         if gossip:
-            params, state = comm_round(state, params)
+            with jax.named_scope(SCOPE_GOSSIP):
+                params, state = comm_round(state, params)
         return params, state, losses
 
     # -- kernel round: flatten once, scan + gossip on the (rows, 1024) layout --
@@ -514,22 +529,26 @@ class PDSGDM:
             # round start: step = (r+1)·p, so r below is the payload round
             r = state["step"] // self.config.p - 1
             gate = (state["mix"]["phase"] > 0).astype(jnp.float32)
-            delta = overlap_begin_mat(mats, r, gate)
+            with jax.named_scope(SCOPE_GOSSIP):
+                delta = overlap_begin_mat(mats, r, gate)
 
             def body(carry, batch):
                 x_mat, mats, step = carry
-                loss, grads = grads_fn(plan.unflatten(x_mat), batch)
-                x_mat, mats = local_step_mat(x_mat, mats,
-                                             plan.flatten(grads), step)
-                if overlap_refresh_mat is not None:
-                    mats = overlap_refresh_mat(mats, delta)
+                with jax.named_scope(SCOPE_GRAD):
+                    loss, grads = grads_fn(plan.unflatten(x_mat), batch)
+                with jax.named_scope(SCOPE_LOCAL_STEP):
+                    x_mat, mats = local_step_mat(x_mat, mats,
+                                                 plan.flatten(grads), step)
+                    if overlap_refresh_mat is not None:
+                        mats = overlap_refresh_mat(mats, delta)
                 return (x_mat, mats, step + 1), loss
 
             (x_mat, mats, step), losses = jax.lax.scan(
                 body, (x_mat, mats, state["step"]), batches)
             if gossip:
-                x_mat, mats = overlap_apply_mat(x_mat, mats, delta,
-                                                step // self.config.p - 1)
+                with jax.named_scope(SCOPE_GOSSIP):
+                    x_mat, mats = overlap_apply_mat(x_mat, mats, delta,
+                                                    step // self.config.p - 1)
             params = plan.unflatten(x_mat)
             state = self.unmat_state(plan, mats, state, step)
             if gossip:
@@ -540,9 +559,11 @@ class PDSGDM:
 
         def body(carry, batch):
             x_mat, mats, step = carry
-            loss, grads = grads_fn(plan.unflatten(x_mat), batch)
-            x_mat, mats = local_step_mat(x_mat, mats, plan.flatten(grads),
-                                         step)
+            with jax.named_scope(SCOPE_GRAD):
+                loss, grads = grads_fn(plan.unflatten(x_mat), batch)
+            with jax.named_scope(SCOPE_LOCAL_STEP):
+                x_mat, mats = local_step_mat(x_mat, mats, plan.flatten(grads),
+                                             step)
             return (x_mat, mats, step + 1), loss
 
         (x_mat, mats, step), losses = jax.lax.scan(
@@ -550,12 +571,15 @@ class PDSGDM:
 
         if gossip and self.kernel_comm_supported:
             r = step // self.config.p - 1
-            x_mat, mats = comm_round_mat(x_mat, mats, plan.row_counts(), r)
+            with jax.named_scope(SCOPE_GOSSIP):
+                x_mat, mats = comm_round_mat(x_mat, mats, plan.row_counts(),
+                                             r)
         params = plan.unflatten(x_mat)
         state = self.unmat_state(plan, mats, state, step)
         if gossip and not self.kernel_comm_supported:
             # e.g. CPD with a non-kernel compressor: tree comm at the boundary
-            params, state = self.comm_round(state, params)
+            with jax.named_scope(SCOPE_GOSSIP):
+                params, state = self.comm_round(state, params)
         return params, state, losses
 
     # -- comm-cost model ----------------------------------------------------------

@@ -34,6 +34,12 @@ from repro.models.moe import MoECfg
 
 __all__ = ["Model", "make_model"]
 
+# Named scopes (``jax.named_scope``) of the attention sub-layers, in every
+# pass: the compiled HLO's op metadata carries them, so a device trace can
+# pick out attention.  Each is scoped by its mixer kind, its pre-norm
+# included.
+ATTENTION_SCOPES = ("attn", "mla")
+
 
 def _noshd(x, *names):
     return x
@@ -139,16 +145,19 @@ class Model:
     def _apply_layer(self, spec: LayerSpec, lp, x, cos, sin, positions):
         cfg = self.cfg
         nap = _norm_apply(cfg)
-        h = nap(lp["norm_mix"], x)
-        if spec.mixer == "attn":
-            mix = attn_lib.attention_apply(lp["attn"], h, self.attn_cfg,
-                                           cos, sin, positions,
-                                           shd=self.shd)
-        elif spec.mixer == "mla":
-            mix = attn_lib.mla_apply(lp["attn"], h, self.attn_cfg,
-                                     cos, sin, positions)
+        if spec.mixer in ATTENTION_SCOPES:
+            with jax.named_scope(spec.mixer):
+                h = nap(lp["norm_mix"], x)
+                if spec.mixer == "attn":
+                    mix = attn_lib.attention_apply(lp["attn"], h,
+                                                   self.attn_cfg, cos, sin,
+                                                   positions, shd=self.shd)
+                else:
+                    mix = attn_lib.mla_apply(lp["attn"], h, self.attn_cfg,
+                                             cos, sin, positions)
         else:
-            mix = mamba_lib.mamba2_apply(lp["mamba"], h, self.mamba_cfg)
+            mix = mamba_lib.mamba2_apply(lp["mamba"], nap(lp["norm_mix"], x),
+                                         self.mamba_cfg)
         x = x + mix
         aux = jnp.zeros((), jnp.float32)
         if spec.ffn == "none":
