@@ -23,6 +23,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core.gossip import CommBackend, DenseComm, HierarchicalComm
 
@@ -38,6 +39,26 @@ SCOPE_GOSSIP = "gossip"          # wire casts, codec, the exchange, the mix
 
 def _tree_map(f, *trees):
     return jax.tree_util.tree_map(f, *trees)
+
+
+def _keep_arg_layout(tree):
+    """Pin every leaf of two or more dims (fewer have one layout) to the
+    default major-to-minor layout, the one the jitted round takes and
+    returns its arrays in.  On a TPU that holds where a leaf's last dim fills
+    whole 128-lane tiles; a narrower leaf whose default layout there differs
+    pays a copy into and out of the loop.
+
+    Applied to the round's scan carry.  Left free, the TPU compiler may carry
+    the loop in another layout: on one chip, where nothing after the loop
+    pins it, it transposes the last two dims of the stacked block weights and
+    momentum, relayouts the whole model into and out of the loop, and holds
+    both copies at once.  Only a TPU compile gets the pin: the CPU's layout
+    assignment keeps the default anyway, and its partitioner would gather
+    the constraint's sharded operand in full."""
+    def pin(tree):
+        return _tree_map(lambda x: x if x.ndim < 2 else with_layout_constraint(
+            x, Layout(major_to_minor=tuple(range(x.ndim)))), tree)
+    return jax.lax.platform_dependent(tree, tpu=pin, default=lambda t: t)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -303,7 +324,7 @@ class PDSGDM:
                     params, state = local_step(state, params, grads)
                     if overlap_refresh is not None:
                         state = overlap_refresh(state, delta)
-                return (params, state), loss
+                return _keep_arg_layout((params, state)), loss
 
             (params, state), losses = jax.lax.scan(body, (params, state),
                                                    batches)
@@ -318,7 +339,7 @@ class PDSGDM:
                 loss, grads = grads_fn(params, batch)
             with jax.named_scope(SCOPE_LOCAL_STEP):
                 params, state = local_step(state, params, grads)
-            return (params, state), loss
+            return _keep_arg_layout((params, state)), loss
 
         (params, state), losses = jax.lax.scan(body, (params, state), batches)
         if gossip:

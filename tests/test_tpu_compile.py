@@ -8,6 +8,8 @@ host.  Shapes are one full-width OLMo-1B block on the flatten-once
 at import time: one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -92,3 +94,76 @@ def test_kernel_compiles_for_v5e(name, one_chip):
              for shape, dtype in args]
     compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# -- the fused round's loop carry ---------------------------------------------
+# One worker on one chip: the W = [[1]] mix folds away, so nothing after the
+# round's p-step loop pins the layout of the params and momentum it carries.
+# Widths are multiples of the chip's 128 lanes, so every leaf's default
+# layout on the chip is major-to-minor, the one the loop is pinned to.
+_ENTRY = re.compile(r"^ENTRY .*?^}", re.S | re.M)
+_PARAM = re.compile(r"^\s+%\S+ = (\w+\[[\d,]*\])(\{[^}]*\}) parameter\(",
+                    re.M)
+_WHILE = re.compile(r"^\s+%\S+ = \((.*?)\) while\(", re.M)
+_COPY = re.compile(r"^\s+%\S+ = (\w+\[[\d,]*\])\{[^}]*\} copy\(", re.M)
+_ARRAY = re.compile(r"(\w+\[[\d,]*\])(\{[^}]*\})")
+
+
+def _dims(layout: str) -> str:
+    """``{2,3,1,0}`` from ``{2,3,1,0:T(8,128)(2,1)S(1)}``: the minor-to-major
+    order alone (tiling and memory space are the chip's own)."""
+    return layout.split(":")[0].rstrip("}") + "}"
+
+
+@pytest.mark.parametrize("optimizer,overlap", [
+    ("pd_sgdm", False), ("cpd_sgdm", False), ("pd_sgdm", True)])
+def test_round_loop_carries_argument_layout(optimizer, overlap, one_chip):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs.base import ModelCfg, OptimCfg, ParallelCfg, RunCfg
+    from repro.configs.shapes import InputShape
+    from repro.launch.runtime import build_train
+
+    model = ModelCfg(name="tiny", arch_type="dense", n_layers=2, d_model=128,
+                     n_heads=4, n_kv_heads=4, d_ff=256, vocab=256,
+                     norm="nonparametric", tie_embeddings=True,
+                     param_dtype="bfloat16", compute_dtype="bfloat16")
+    run = RunCfg(model=model, parallel=ParallelCfg(remat="full"),
+                 optim=OptimCfg(name=optimizer, p=4, overlap=overlap))
+    mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    pack = build_train(run, mesh, InputShape("t", 32, 2, "train"))
+
+    def struct(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=sh), tree, shardings)
+    hlo = pack.train_round.lower(
+        struct(pack.params_struct, pack.params_sharding),
+        struct(pack.state_struct, pack.state_sharding),
+        struct(pack.round_batch_struct, pack.round_batch_sharding),
+    ).compile().as_text()
+    entry = _ENTRY.search(hlo).group(0)
+
+    leaves = {}   # whole param / momentum leaf -> its argument layouts
+    for shape, layout in _PARAM.findall(entry):
+        leaves.setdefault(shape, set()).add(_dims(layout))
+    hlo_dtype = {"bfloat16": "bf16", "float32": "f32"}
+    whole = set()  # each param and its f32 momentum, as the HLO writes them
+    for s in jax.tree_util.tree_leaves(pack.params_struct):
+        if s.ndim >= 2:
+            dims = ",".join(map(str, s.shape))
+            whole |= {f"{hlo_dtype[jnp.dtype(s.dtype).name]}[{dims}]",
+                      f"f32[{dims}]"}
+    assert whole <= set(leaves), (whole, leaves)
+
+    loops = _WHILE.findall(entry)
+    assert loops, "the round's p-step loop was not found"
+    carried = [(shape, _dims(layout)) for loop in loops
+               for shape, layout in _ARRAY.findall(loop) if shape in whole]
+    assert carried, "the loop carries no param or momentum leaf"
+    moved = sorted({(s, l) for s, l in carried if l not in leaves[s]})
+    assert not moved, f"the loop carries leaves in another layout: {moved}"
+    copies = sorted({s for s in _COPY.findall(entry) if s in whole})
+    assert not copies, f"ENTRY relayouts whole leaves: {copies}"
