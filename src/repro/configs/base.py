@@ -31,9 +31,20 @@ class ModelCfg:
     qkv_bias: bool = False
     window: Optional[int] = None    # sliding-window attention
     rope_theta: float = 10000.0
+    # positions in attention: "rope" (rotary) | "none" (NoPE)
+    attn_positions: str = "rope"
+    attn_scale: Optional[float] = None  # softmax scale; None = head_dim**-0.5
+    norm_eps: Optional[float] = None    # None = each norm's own default
+    # --- μP-style multipliers (Granite): the embedding times embed_scale,
+    # each mixer's and MLP's output times residual_scale before its
+    # residual add, the logits divided by logits_scale
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logits_scale: float = 1.0
     gated_mlp: bool = True
     tie_embeddings: bool = False
-    # --- block pattern (repeated n_layers / len(pattern) times)
+    # --- block pattern (repeated n_layers / len(pattern) times); also
+    # accepts a sequence of (mixer, ffn) pairs, as a JSON file states it
     pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
     # --- MoE
     n_experts: int = 0
@@ -66,6 +77,12 @@ class ModelCfg:
     source: str = ""
 
     def __post_init__(self):
+        object.__setattr__(self, "pattern", tuple(
+            s if isinstance(s, LayerSpec) else LayerSpec(*s)
+            for s in self.pattern))
+        if self.attn_positions not in ("rope", "none"):
+            raise ValueError(f"{self.name}: attn_positions "
+                             f"{self.attn_positions!r} is not rope|none")
         if self.n_layers % len(self.pattern) != 0:
             raise ValueError(
                 f"{self.name}: n_layers {self.n_layers} not divisible by "

@@ -4,10 +4,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List
 
-from repro.configs import (arctic_480b, internvl2_76b, jamba_1_5_large_398b,
-                           mamba2_1_3b, minicpm3_4b, mixtral_8x7b,
-                           musicgen_medium, olmo_1b, paper_resnet20,
-                           qwen2_72b, stablelm_12b)
+from repro.configs import (arctic_480b, granite_4_0_h_micro, internvl2_76b,
+                           jamba_1_5_large_398b, mamba2_1_3b, minicpm3_4b,
+                           mixtral_8x7b, musicgen_medium, olmo_1b,
+                           paper_resnet20, qwen2_72b, stablelm_12b)
 from repro.configs.base import LayerSpec, ModelCfg, RunCfg
 from repro.configs.shapes import SHAPES, InputShape
 
@@ -25,6 +25,7 @@ ARCHS = {
     "internvl2-76b": internvl2_76b.config,
     "jamba-1.5-large-398b": jamba_1_5_large_398b.config,
     "mamba2-1.3b": mamba2_1_3b.config,
+    "granite-4.0-h-micro": granite_4_0_h_micro.config,
     "paper-resnet20": paper_resnet20.config,
 }
 
@@ -73,8 +74,14 @@ def get_smoke_config(name: str) -> RunCfg:
     if m.arch_type == "cnn":
         return run
     pattern = m.pattern
-    if len(pattern) > 2:  # jamba: keep hybrid character in 2 layers
-        pattern = (LayerSpec("mamba", "dense"), LayerSpec("attn", "moe"))
+    if len(pattern) > 2:
+        # a hybrid: keep every mixer and ffn kind the pattern has, each
+        # in order of first appearance, the shorter list cycled
+        mixers = list(dict.fromkeys(s.mixer for s in pattern))
+        ffns = list(dict.fromkeys(s.ffn for s in pattern))
+        n = max(len(mixers), len(ffns))
+        pattern = tuple(LayerSpec(mixers[i % len(mixers)],
+                                  ffns[i % len(ffns)]) for i in range(n))
     n_layers = 2 if len(pattern) <= 2 else len(pattern)
     small = dataclasses.replace(
         m,

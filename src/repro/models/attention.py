@@ -39,6 +39,8 @@ class AttnCfg:
     q_chunk: int = 1024      # blockwise query-chunk length  # lint: allow
     blockwise_threshold: int = 8192  # use blockwise when seq >= this
     rope_theta: float = 10000.0
+    rope: bool = True               # False: no positional embedding (NoPE)
+    scale: Optional[float] = None   # softmax scale; None = head_dim**-0.5
     # MLA dims (minicpm3 / deepseek-v2 style); used only by the mla_* path
     q_lora_rank: int = 768
     kv_lora_rank: int = 256
@@ -65,9 +67,14 @@ def _qkv(params, x, cfg: AttnCfg, cos, sin, positions):
     q = dense(params["wq"], x).reshape(b, s, h, hd)
     k = dense(params["wk"], x).reshape(b, s, kvh, hd)
     v = dense(params["wv"], x).reshape(b, s, kvh, hd)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+    if cfg.rope:
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
     return q, k, v
+
+
+def _scale(cfg: AttnCfg, head_dim: int) -> float:
+    return cfg.scale if cfg.scale is not None else head_dim ** -0.5
 
 
 def _scores_to_out(q, k, v, mask, scale):
@@ -92,7 +99,7 @@ def _group(q, cfg: AttnCfg):
 
 def attend_full(q, k, v, cfg: AttnCfg, q_positions, k_positions):
     """Materialized causal (+optional sliding-window) attention."""
-    scale = cfg.head_dim ** -0.5
+    scale = _scale(cfg, cfg.head_dim)
     qg = _group(q, cfg)
     caus = q_positions[:, :, None] >= k_positions[:, None, :]
     if cfg.window is not None:
@@ -109,7 +116,7 @@ def attend_blockwise(q, k, v, cfg: AttnCfg, q_positions, k_positions):
     cq = min(cfg.q_chunk, s)
     assert s % cq == 0, f"seq {s} not divisible by q_chunk {cq}"
     nchunks = s // cq
-    scale = hd ** -0.5
+    scale = _scale(cfg, hd)
     qg = _group(q, cfg)
 
     if cfg.window is not None:
@@ -231,7 +238,7 @@ def attention_decode(params, x, cache, pos, cfg: AttnCfg, cos, sin):
     cpos = jax.lax.dynamic_update_slice_in_dim(
         cache["pos"], positions, slot, axis=1)
 
-    scale = cfg.head_dim ** -0.5
+    scale = _scale(cfg, cfg.head_dim)
     qg = _group(q, cfg)
     mask = (cpos >= 0) & (cpos <= pos)
     if cfg.window is not None:

@@ -34,6 +34,7 @@ class Mamba2Cfg:
     conv_kernel: int = 4
     n_groups: int = 1
     bcast_groups: bool = False  # broadcast (not gather) group->head expand
+    norm_eps: float = 1e-6      # the gated RMSNorm's eps
 
     @property
     def d_inner(self) -> int:
@@ -174,7 +175,8 @@ def mamba2_apply(params, u, cfg: Mamba2Cfg, return_state: bool = False):
     # gated RMSNorm then output projection
     y = rmsnorm(params["norm"],
                 (y.astype(jnp.float32)
-                 * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype))
+                 * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype),
+                eps=cfg.norm_eps)
     out = dense(params["out_proj"], y)
     if not return_state:
         return out
@@ -226,5 +228,6 @@ def mamba2_decode(params, u, cache, cfg: Mamba2Cfg):
     y = y.reshape(bsz, 1, cfg.d_inner).astype(u.dtype)
     y = rmsnorm(params["norm"],
                 (y.astype(jnp.float32)
-                 * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype))
+                 * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype),
+                eps=cfg.norm_eps)
     return dense(params["out_proj"], y), {"ssm": S, "conv": new_conv}

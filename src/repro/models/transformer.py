@@ -39,6 +39,9 @@ __all__ = ["Model", "make_model"]
 # pick out attention.  Each is scoped by its mixer kind, its pre-norm
 # included.
 ATTENTION_SCOPES = ("attn", "mla")
+# The Mamba-2 mixer's scope, its pre-norm included: a kind of its own, not
+# an attention scope.
+SSM_SCOPE = "ssm"
 
 
 def _noshd(x, *names):
@@ -57,12 +60,13 @@ def _norm_init(cfg: ModelCfg, dtype):
 
 
 def _norm_apply(cfg: ModelCfg):
+    eps = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
     if cfg.norm == "rmsnorm":
-        return rmsnorm
+        return partial(rmsnorm, **eps)
     if cfg.norm == "layernorm":
-        return layernorm
+        return partial(layernorm, **eps)
     if cfg.norm == "nonparametric":
-        return lambda p, x: nonparametric_layernorm(x)
+        return lambda p, x: nonparametric_layernorm(x, **eps)
     raise ValueError(cfg.norm)
 
 
@@ -78,14 +82,16 @@ class Model:
             d_model=cfg.d_model, n_heads=cfg.n_heads,
             n_kv_heads=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
             qkv_bias=cfg.qkv_bias, window=cfg.window,
-            rope_theta=cfg.rope_theta,
+            rope_theta=cfg.rope_theta, rope=cfg.attn_positions == "rope",
+            scale=cfg.attn_scale,
             q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
             qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
             v_head_dim=cfg.v_head_dim)
         self.mamba_cfg = Mamba2Cfg(
             d_model=cfg.d_model, d_state=cfg.ssm_state,
             headdim=cfg.ssm_headdim, expand=cfg.ssm_expand,
-            chunk=cfg.ssm_chunk, bcast_groups=cfg.ssm_bcast_groups)
+            chunk=cfg.ssm_chunk, bcast_groups=cfg.ssm_bcast_groups,
+            **({} if cfg.norm_eps is None else {"norm_eps": cfg.norm_eps}))
         self.moe_cfg = MoECfg(
             d_model=cfg.d_model, d_ff=cfg.d_ff, n_experts=cfg.n_experts,
             top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
@@ -142,6 +148,12 @@ class Model:
                           if not self.cfg.use_mla else self.cfg.qk_rope_dim,
                           max_len, self.cfg.rope_theta)
 
+    def _residual(self, x, y):
+        """x + y, the branch ``y`` times the configured residual scale."""
+        if self.cfg.residual_scale != 1.0:
+            y = y * jnp.asarray(self.cfg.residual_scale, y.dtype)
+        return x + y
+
     def _apply_layer(self, spec: LayerSpec, lp, x, cos, sin, positions):
         cfg = self.cfg
         nap = _norm_apply(cfg)
@@ -156,9 +168,10 @@ class Model:
                     mix = attn_lib.mla_apply(lp["attn"], h, self.attn_cfg,
                                              cos, sin, positions)
         else:
-            mix = mamba_lib.mamba2_apply(lp["mamba"], nap(lp["norm_mix"], x),
-                                         self.mamba_cfg)
-        x = x + mix
+            with jax.named_scope(SSM_SCOPE):
+                mix = mamba_lib.mamba2_apply(
+                    lp["mamba"], nap(lp["norm_mix"], x), self.mamba_cfg)
+        x = self._residual(x, mix)
         aux = jnp.zeros((), jnp.float32)
         if spec.ffn == "none":
             return x, aux
@@ -169,14 +182,20 @@ class Model:
         if spec.ffn in ("moe", "dense+moe"):
             mo, aux = moe_lib.moe_apply(lp["moe"], h, self.moe_cfg, self.shd)
             out = out + mo
-        x = self.shd(x + out, "batch", "seq", "embed")
+        x = self.shd(self._residual(x, out), "batch", "seq", "embed")
         return x, aux
 
-    def _block(self, x, block_params, cos, sin, positions):
+    def _block(self, x, block_params, cos, sin, positions,
+               remat: str = "none"):
+        """One repeat of the pattern; with ``remat="full"`` each layer is
+        its own checkpoint, so the backward holds one layer's
+        intermediates at a time."""
         aux_total = jnp.zeros((), jnp.float32)
         for pos, spec in enumerate(self.cfg.pattern):
-            x, aux = self._apply_layer(spec, block_params[f"pos{pos}"],
-                                       x, cos, sin, positions)
+            layer = partial(self._apply_layer, spec)
+            if remat == "full":
+                layer = jax.checkpoint(layer, prevent_cse=False)
+            x, aux = layer(block_params[f"pos{pos}"], x, cos, sin, positions)
             aux_total = aux_total + aux
         return x, aux_total
 
@@ -194,7 +213,19 @@ class Model:
                                 axis=1)
         else:
             raise ValueError(cfg.input_mode)
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, cd)
         return self.shd(x, "batch", "seq", "embed")
+
+    def _logits(self, params, x):
+        """f32 logits of the final-normed ``x``, over the logits scale."""
+        head = (params["embed"]["table"].T if self.cfg.tie_embeddings
+                else params["lm_head"]["w"])
+        logits = jnp.einsum("bsd,dv->bsv", x, head,
+                            preferred_element_type=jnp.float32)
+        if self.cfg.logits_scale != 1.0:
+            logits = logits / self.cfg.logits_scale
+        return logits
 
     # ------------------------------------------------------------------ forward
     def apply(self, params, batch, remat: str = "none"):
@@ -207,19 +238,14 @@ class Model:
 
         def block_fn(carry, block_params):
             x, aux = carry
-            x, a = self._block(x, block_params, cos, sin, positions)
+            x, a = self._block(x, block_params, cos, sin, positions, remat)
             return (x, aux + a), None
 
-        if remat == "full":
-            block_fn = jax.checkpoint(block_fn, prevent_cse=False)
         (x, aux), _ = jax.lax.scan(
             block_fn, (x, jnp.zeros((), jnp.float32)), params["blocks"])
 
         x = _norm_apply(cfg)(params["final_norm"], x)
-        head = (params["embed"]["table"].T if cfg.tie_embeddings
-                else params["lm_head"]["w"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+        logits = self._logits(params, x)
         return self.shd(logits, "batch", "seq", "vocab"), aux
 
     def loss(self, params, batch, remat: str = "none"):
@@ -273,7 +299,7 @@ class Model:
         else:
             mix, cache = mamba_lib.mamba2_apply(
                 lp["mamba"], h, self.mamba_cfg, return_state=True)
-        x = x + mix
+        x = self._residual(x, mix)
         if spec.ffn == "none":
             return x, cache
         h = nap(lp["norm_ffn"], x)
@@ -283,7 +309,7 @@ class Model:
         if spec.ffn in ("moe", "dense+moe"):
             mo, _ = moe_lib.moe_apply(lp["moe"], h, self.moe_cfg, self.shd)
             out = out + mo
-        return x + out, cache
+        return self._residual(x, out), cache
 
     def prefill_fast(self, params, batch, max_len: Optional[int] = None):
         """One-pass prompt processing: last-token logits + populated cache.
@@ -310,10 +336,7 @@ class Model:
 
         x, cache = jax.lax.scan(block_fn, x, params["blocks"])
         x = _norm_apply(cfg)(params["final_norm"], x[:, -1:, :])
-        head = (params["embed"]["table"].T if cfg.tie_embeddings
-                else params["lm_head"]["w"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+        logits = self._logits(params, x)
         return logits[:, 0, :], cache
 
     def _decode_layer(self, spec: LayerSpec, lp, x, cache, pos, cos, sin):
@@ -329,7 +352,7 @@ class Model:
         else:
             mix, cache = mamba_lib.mamba2_decode(
                 lp["mamba"], h, cache, self.mamba_cfg)
-        x = x + mix
+        x = self._residual(x, mix)
         if spec.ffn == "none":
             return x, cache
         h = nap(lp["norm_ffn"], x)
@@ -339,7 +362,7 @@ class Model:
         if spec.ffn in ("moe", "dense+moe"):
             mo, _ = moe_lib.moe_apply(lp["moe"], h, self.moe_cfg, self.shd)
             out = out + mo
-        return x + out, cache
+        return self._residual(x, out), cache
 
     def decode_step(self, params, cache, tokens_or_embeds, pos,
                     max_positions: Optional[int] = None):
@@ -358,6 +381,8 @@ class Model:
             x = embed(params["embed"], tokens_or_embeds[:, None]).astype(cd)
         else:
             x = tokens_or_embeds.astype(cd)
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, cd)
         max_len = max_positions or self._cache_len(cache)
         cos, sin = self._rope(max_len)
 
@@ -373,10 +398,7 @@ class Model:
 
         x, new_cache = jax.lax.scan(block_fn, x, (params["blocks"], cache))
         x = _norm_apply(cfg)(params["final_norm"], x)
-        head = (params["embed"]["table"].T if cfg.tie_embeddings
-                else params["lm_head"]["w"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+        logits = self._logits(params, x)
         return logits[:, 0, :], new_cache
 
     def _cache_len(self, cache) -> int:
@@ -430,10 +452,7 @@ class Model:
 
         x, new_cache = jax.lax.scan(block_fn, x, (params["blocks"], cache))
         x = _norm_apply(cfg)(params["final_norm"], x)
-        head = (params["embed"]["table"].T if cfg.tie_embeddings
-                else params["lm_head"]["w"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+        logits = self._logits(params, x)
         return logits[:, 0, :], new_cache
 
 

@@ -89,6 +89,15 @@ def test_full_config_matches_assignment(arch):
                                      n_experts=16),
         "mamba2-1.3b": dict(n_layers=48, d_model=2048, d_ff=0,
                             vocab=50280, ssm_state=128),
+        "granite-4.0-h-micro": dict(n_layers=40, d_model=2048, n_heads=32,
+                                    n_kv_heads=8, head_dim=64, d_ff=8192,
+                                    vocab=100352, ssm_state=128,
+                                    ssm_headdim=64, ssm_chunk=256,
+                                    n_experts=0, tie_embeddings=True,
+                                    attn_positions="none",
+                                    attn_scale=1 / 64, embed_scale=12.0,
+                                    residual_scale=0.22, logits_scale=8.0,
+                                    norm_eps=1e-5),
     }[arch]
     m = get_config(arch).model
     for k, v in spec.items():
@@ -102,3 +111,20 @@ def test_jamba_interleave_ratio():
     assert mixers.count("attn") == 1 and mixers.count("mamba") == 7
     ffns = [s.ffn for s in m.pattern]
     assert ffns.count("moe") == 4  # every other layer
+
+
+def test_granite_interleave_ratio():
+    m = get_config("granite-4.0-h-micro").model
+    mixers = [s.mixer for s in m.pattern] * m.n_repeats
+    assert [i for i, k in enumerate(mixers) if k == "attn"] == [5, 15, 25, 35]
+    assert mixers.count("mamba") == 36
+    assert {s.ffn for s in m.pattern} == {"dense"}
+
+
+@pytest.mark.parametrize("arch", ["jamba-1.5-large-398b",
+                                  "granite-4.0-h-micro"])
+def test_smoke_config_keeps_every_layer_kind_of_a_hybrid(arch):
+    full, small = get_config(arch).model, get_smoke_config(arch).model
+    assert {s.mixer for s in small.pattern} == {s.mixer for s in full.pattern}
+    assert {s.ffn for s in small.pattern} == {s.ffn for s in full.pattern}
+    assert small.n_experts == min(full.n_experts, 4)

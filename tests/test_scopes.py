@@ -89,14 +89,23 @@ def _model_segments(arch: str):
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
     grad = jax.jit(jax.grad(lambda p, b: model.loss(p, b)[0]))
-    text = grad.lower(params, {"tokens": tok, "labels": tok}).compile()
-    return _segments(text.as_text()), {spec.mixer for spec in cfg.pattern}
+    text = grad.lower(params, {"tokens": tok,
+                               "labels": tok}).compile().as_text()
+    return (_segments(text), {spec.mixer for spec in cfg.pattern},
+            re.findall(r'op_name="([^"]*)"', text))
 
 
 @pytest.mark.parametrize("arch", ("minicpm3-4b", "jamba-1.5-large-398b",
-                                  "mamba2-1.3b"))
+                                  "mamba2-1.3b", "granite-4.0-h-micro"))
 def test_each_attention_kind_is_scoped_by_its_mixer(arch):
-    segs, mixers = _model_segments(arch)
+    segs, mixers, op_names = _model_segments(arch)
     for kind in transformer.ATTENTION_SCOPES:
         assert (kind in segs) == (kind in mixers), kind
+    # the Mamba-2 mixer has a scope of its own, on no op of an attention
+    # sub-layer
+    assert (transformer.SSM_SCOPE in segs) == ("mamba" in mixers)
     assert "mamba" not in segs
+    attention = set(transformer.ATTENTION_SCOPES)
+    for op_name in op_names:
+        op_segs = _segments(f'op_name="{op_name}"')
+        assert not (transformer.SSM_SCOPE in op_segs and op_segs & attention)

@@ -115,20 +115,17 @@ def _dims(layout: str) -> str:
     return layout.split(":")[0].rstrip("}") + "}"
 
 
-@pytest.mark.parametrize("optimizer,overlap", [
-    ("pd_sgdm", False), ("cpd_sgdm", False), ("pd_sgdm", True)])
-def test_round_loop_carries_argument_layout(optimizer, overlap, one_chip):
+def _round_entry(model, optimizer, overlap, one_chip):
+    """The ENTRY computation of a one-worker round compiled for the chip,
+    with each param and momentum leaf of two or more dims as the HLO writes
+    it, and the layouts its arguments come in."""
     import numpy as np
     from jax.sharding import Mesh
 
-    from repro.configs.base import ModelCfg, OptimCfg, ParallelCfg, RunCfg
+    from repro.configs.base import OptimCfg, ParallelCfg, RunCfg
     from repro.configs.shapes import InputShape
     from repro.launch.runtime import build_train
 
-    model = ModelCfg(name="tiny", arch_type="dense", n_layers=2, d_model=128,
-                     n_heads=4, n_kv_heads=4, d_ff=256, vocab=256,
-                     norm="nonparametric", tie_embeddings=True,
-                     param_dtype="bfloat16", compute_dtype="bfloat16")
     run = RunCfg(model=model, parallel=ParallelCfg(remat="full"),
                  optim=OptimCfg(name=optimizer, p=4, overlap=overlap))
     mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1),
@@ -150,20 +147,65 @@ def test_round_loop_carries_argument_layout(optimizer, overlap, one_chip):
     for shape, layout in _PARAM.findall(entry):
         leaves.setdefault(shape, set()).add(_dims(layout))
     hlo_dtype = {"bfloat16": "bf16", "float32": "f32"}
-    whole = set()  # each param and its f32 momentum, as the HLO writes them
+    whole = {}    # each param and its f32 momentum -> its last dim
     for s in jax.tree_util.tree_leaves(pack.params_struct):
         if s.ndim >= 2:
             dims = ",".join(map(str, s.shape))
-            whole |= {f"{hlo_dtype[jnp.dtype(s.dtype).name]}[{dims}]",
-                      f"f32[{dims}]"}
-    assert whole <= set(leaves), (whole, leaves)
+            for t in (hlo_dtype[jnp.dtype(s.dtype).name], "f32"):
+                whole[f"{t}[{dims}]"] = s.shape[-1]
+    assert set(whole) <= set(leaves), (whole, leaves)
+    return entry, whole, leaves
 
+
+def _moved_and_copied(entry, whole, leaves):
+    """The leaves the round's loop carries in a layout their arguments do
+    not come in, and the whole leaves ENTRY copies."""
     loops = _WHILE.findall(entry)
     assert loops, "the round's p-step loop was not found"
     carried = [(shape, _dims(layout)) for loop in loops
                for shape, layout in _ARRAY.findall(loop) if shape in whole]
     assert carried, "the loop carries no param or momentum leaf"
     moved = sorted({(s, l) for s, l in carried if l not in leaves[s]})
-    assert not moved, f"the loop carries leaves in another layout: {moved}"
     copies = sorted({s for s in _COPY.findall(entry) if s in whole})
+    return moved, copies
+
+
+@pytest.mark.parametrize("optimizer,overlap", [
+    ("pd_sgdm", False), ("cpd_sgdm", False), ("pd_sgdm", True)])
+def test_round_loop_carries_argument_layout(optimizer, overlap, one_chip):
+    from repro.configs.base import ModelCfg
+
+    model = ModelCfg(name="tiny", arch_type="dense", n_layers=2, d_model=128,
+                     n_heads=4, n_kv_heads=4, d_ff=256, vocab=256,
+                     norm="nonparametric", tie_embeddings=True,
+                     param_dtype="bfloat16", compute_dtype="bfloat16")
+    moved, copies = _moved_and_copied(
+        *_round_entry(model, optimizer, overlap, one_chip))
+    assert not moved, f"the loop carries leaves in another layout: {moved}"
     assert not copies, f"ENTRY relayouts whole leaves: {copies}"
+
+
+def test_hybrid_round_compiles_and_keeps_argument_layout(one_chip):
+    """A small Granite round (one 10-layer period of Mamba-2 and NoPE
+    attention, μP multipliers, a checkpoint per layer) compiles for the
+    chip, and every lane-aligned leaf it carries keeps its arguments'
+    layout.  A leaf whose last dim is not a whole number of 128-lane tiles
+    may come in the chip's own layout, which the round's loop does not
+    pin (ROADMAP D10).  Here those are Mamba-2's in-projection (128 × 560;
+    2048 × 8512 at full width) and, at this size only, the k and v
+    projections of the one KV head (128 × 32): each is carried, and copied
+    by ENTRY, into and out of the major-to-minor layout.  Only such leaves
+    may move."""
+    import dataclasses
+
+    from repro.configs.registry import get_config
+
+    model = dataclasses.replace(
+        get_config("granite-4.0-h-micro").model, n_layers=10, d_model=128,
+        n_heads=4, n_kv_heads=1, head_dim=32, d_ff=256, vocab=256,
+        ssm_state=16, ssm_headdim=16, ssm_chunk=8)
+    entry, whole, leaves = _round_entry(model, "pd_sgdm", False, one_chip)
+    moved, copies = _moved_and_copied(entry, whole, leaves)
+    aligned = {s for s, last in whole.items() if last % LANE == 0}
+    assert not [m for m in moved if m[0] in aligned], moved
+    assert not [c for c in copies if c in aligned], copies
